@@ -1,8 +1,7 @@
-"""Kernel micro-benchmarks: Pallas (interpret) vs jnp reference.
+"""Kernel micro-benchmarks: Pallas vs jnp reference.
 
-On this CPU container interpret-mode timing is NOT TPU performance — the
-numbers recorded here are correctness-path timings; TPU perf is reasoned
-structurally in EXPERIMENTS.md §Roofline."""
+On a CPU the Pallas kernels run in the interpreter, so those timings are
+correctness-path timings, not TPU performance."""
 from __future__ import annotations
 
 import time
@@ -11,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 
 def _time(fn, *args, reps=3):
@@ -36,8 +35,9 @@ def run(rows):
     seed = jnp.asarray(rng.integers(0, 2**32, (n, C), dtype=np.uint32))
     cnt = jnp.full((n,), C, jnp.int32)
     kw = dict(n_objects=64, lookahead=0.5, K=S // 32, KR=3, dist="dyadic")
-    for impl, flag in (("pallas_interp", True), ("jnp_ref", False)):
-        f = jax.jit(lambda *a: ops.event_apply(*a, **kw, use_pallas=flag))
+    for impl, fn in (("pallas", ops.event_apply),
+                     ("jnp_ref", ref.event_apply_ref)):
+        f = jax.jit(lambda *a, fn=fn: fn(*a, **kw))
         dt = _time(f, payload, addresses, top, ts, seed, cnt)
         rows.append({"name": f"kernel_event_apply_{impl}",
                      "us_per_call": 1e6 * dt,

@@ -56,8 +56,10 @@ _CHILD = textwrap.dedent("""
     import numpy as np, jax
     from jax.sharding import Mesh
     from repro.core.engine import AXIS, EngineConfig, ParsirEngine
+    from repro.launch.runtime import enable_compile_cache
     from repro.workloads.registry import get_workload
 
+    enable_compile_cache()
     spec = json.loads(sys.argv[1])
     D = spec["devices"]
     mesh = Mesh(np.array(jax.devices()[:D]), (AXIS,))
@@ -458,7 +460,8 @@ def run_child(devices: int, workload: str, **spec):
     merged = dict(BASE, devices=devices, workload=workload,
                   model_kw=model_kw, **spec)
     env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                        f"platform_device_count={devices}").strip()
     env["PYTHONPATH"] = "src"
     r = subprocess.run([sys.executable, "-c", _CHILD, json.dumps(merged)],
                        env=env, capture_output=True, text=True, timeout=2400)

@@ -1,7 +1,8 @@
 """Paper Fig 3 analogue: strong scaling — fixed model, growing worker count.
 
 Host devices stand in for CPUs/chips (subprocess per device count since JAX
-locks the device count at first init)."""
+locks the device count at first init).  The caller must not hold the device
+while the children run; a child that fails fails the sweep."""
 from __future__ import annotations
 
 import json
@@ -19,6 +20,7 @@ _CHILD = textwrap.dedent("""
     from common import build, throughput
 
     n = int(sys.argv[1])
+    assert len(jax.devices()) >= n, f"{len(jax.devices())} devices, need {n}"
     mesh = Mesh(np.array(jax.devices()[:n]), (AXIS,))
     eng = build(o=512, m=20, s=256, lookahead=0.5, dist="exponential",
                 mesh=mesh)
@@ -30,14 +32,14 @@ _CHILD = textwrap.dedent("""
 def run(rows):
     for n in (1, 2, 4, 8):
         env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                            f"platform_device_count={n}").strip()
         env["PYTHONPATH"] = "src"
         r = subprocess.run([sys.executable, "-c", _CHILD, str(n)], env=env,
                            capture_output=True, text=True, timeout=1200)
         if r.returncode != 0:
-            rows.append({"name": f"fig3_scaling_W{n}", "us_per_call": -1,
-                         "derived": f"error={r.stderr[-200:]}"})
-            continue
+            raise RuntimeError(f"fig3 scaling child W={n} failed:\n"
+                               f"{r.stderr[-2000:]}")
         d = json.loads(r.stdout.strip().splitlines()[-1])
         rows.append({
             "name": f"fig3_scaling_W{n}",

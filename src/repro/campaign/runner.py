@@ -83,16 +83,14 @@ def run_campaign(spec: CampaignSpec, store: ResultsStore | None = None,
     from jax.sharding import Mesh
 
     from ..core.engine import AXIS
+    from ..launch.runtime import too_few_devices
 
     say = log or (lambda msg: None)
     rep_shards = None
     if mesh is None:
         devs = jax.devices()
         if len(devs) < spec.devices:
-            raise ValueError(
-                f"{len(devs)} devices visible, campaign wants {spec.devices} "
-                f"— set XLA_FLAGS=--xla_force_host_platform_device_count="
-                f"{spec.devices}")
+            raise ValueError(too_few_devices(len(devs), spec.devices))
         if spec.devices > 1 and len(spec.seeds) % spec.devices == 0:
             # the campaign throughput layout: shard the REPLICATION axis —
             # each replication runs whole (collective-free) on its own

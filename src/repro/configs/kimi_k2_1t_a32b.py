@@ -3,7 +3,7 @@ vocab=163840, MoE 384e top-8 — trillion-param MoE (paper-table).
 [arXiv:2501.kimi2; unverified]
 
 Per the assignment table this uses GQA (kv=8) attention; the released K2 uses
-MLA — we follow the table (noted in DESIGN.md).  1 shared expert (K2 style).
+MLA — we follow the table.  1 shared expert (K2 style).
 """
 from .base import ModelConfig
 

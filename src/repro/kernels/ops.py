@@ -1,22 +1,34 @@
 """jit'd public wrappers for the Pallas kernels (+ layout/padding adapters).
 
-Every op takes ``use_pallas``/``interpret`` switches: on this CPU container the
-kernels execute via ``interpret=True`` (validated against ref.py); on real TPU
-hardware the same pallas_call lowers to Mosaic.  The pure-jnp fallbacks are the
-production path used by the dry-run (XLA:CPU cannot compile Mosaic kernels).
+:func:`event_apply` — the simulator's kernel — derives its mode from the
+platform (:func:`interpret_mode`): the Pallas interpreter on ``cpu``, Mosaic
+on ``tpu``, an error anywhere else, so it never runs interpreted on a chip.
+``ref.py`` holds the pure-jnp oracles the kernels are tested against.  The
+attention and SSD ops are seed LLM scaffolding and keep their explicit
+``use_pallas``/``interpret`` switches.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 
 from . import ref
-from .event_apply import build_event_apply
+from .event_apply import LANE, OBJ_BLOCK, build_event_apply
 from .flash_attention import flash_attention
 from .ssd_scan import ssd_scan
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter on this platform:
+    ``cpu`` interprets, ``tpu`` compiles with Mosaic, anything else fails."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas kernel path for platform {platform!r}")
 
 
 # -- event apply -------------------------------------------------------------
@@ -33,20 +45,30 @@ def _event_apply_cached(S, LANES, C, K, KR, n_objects, lookahead, dist, mean,
 
 def event_apply(payload, addresses, top, ts, seed, cnt, *, n_objects: int,
                 lookahead: float, K: int, KR: int, dist: str = "dyadic",
-                mean: float = 1.0, interpret: bool = True,
-                use_pallas: bool = True, hot_objects: int = 0,
-                hot_prob: int = 0):
-    """Batched per-object event application.  payload: [n, LANES, S]."""
+                mean: float = 1.0, hot_objects: int = 0, hot_prob: int = 0):
+    """Batched per-object event application.  payload: [n, LANES, S].
+
+    Pads objects to the kernel's 8-object block (pad rows have ``cnt`` 0)
+    and events to whole 128-lane tiles, then slices the result back; the
+    outputs match :func:`repro.kernels.ref.event_apply_ref` bit for bit."""
     n, LANES, S = payload.shape
     C = ts.shape[1]
-    if not use_pallas:
-        return ref.event_apply_ref(payload, addresses, top, ts, seed, cnt,
-                                   n_objects=n_objects, lookahead=lookahead,
-                                   K=K, KR=KR, dist=dist, mean=mean,
-                                   hot_objects=hot_objects, hot_prob=hot_prob)
-    fn = _event_apply_cached(S, LANES, C, K, KR, n_objects, lookahead, dist,
-                             mean, interpret, hot_objects, hot_prob)
-    return fn(payload, addresses, top, ts, seed, cnt)
+    pn, pc = -n % OBJ_BLOCK, -C % LANE
+    if pn:
+        rows = lambda x, v=0: jnp.pad(x, ((0, pn),) + ((0, 0),) * (x.ndim - 1),
+                                      constant_values=v)
+        payload, addresses, top, cnt = (rows(payload), rows(addresses),
+                                        rows(top), rows(cnt))
+        ts, seed = rows(ts, jnp.inf), rows(seed)
+    if pc:
+        ts = jnp.pad(ts, ((0, 0), (0, pc)), constant_values=jnp.inf)
+        seed = jnp.pad(seed, ((0, 0), (0, pc)))
+    fn = _event_apply_cached(S, LANES, C + pc, K, KR, n_objects, lookahead,
+                             dist, mean, interpret_mode(), hot_objects,
+                             hot_prob)
+    pay, addr, *emitted = fn(payload, addresses, top, ts, seed, cnt)
+    emitted = [e[:n, :C] for e in emitted]
+    return (pay[:n], addr[:n], top[:n], *emitted)
 
 
 # -- attention ----------------------------------------------------------------

@@ -95,7 +95,9 @@ def main():
     args = ap.parse_args()
 
     from ..campaign import CampaignSpec, ResultsStore, run_campaign
+    from .runtime import enable_compile_cache
 
+    enable_compile_cache()
     spec = CampaignSpec(
         workload=args.workload,
         seeds=tuple(range(args.seed_base, args.seed_base + args.seeds)),

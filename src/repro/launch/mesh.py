@@ -10,11 +10,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where this jax version has them
-    (jax <= 0.4.x predates jax.sharding.AxisType; default semantics match)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    kw = {"axis_types": (at.Auto,) * len(axes)} if at is not None else {}
-    return jax.make_mesh(shape, axes, **kw)
+    """jax.make_mesh with every axis of type Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

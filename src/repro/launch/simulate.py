@@ -109,13 +109,12 @@ def main():
     from ..core.engine import AXIS, EngineConfig, ParsirEngine
     from ..testing import assert_clean
     from ..workloads.registry import get_workload
+    from .runtime import enable_compile_cache, too_few_devices
 
+    enable_compile_cache()
     devs = jax.devices()
     if len(devs) < args.devices:
-        raise SystemExit(
-            f"{len(devs)} devices visible, need {args.devices} — set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{args.devices}")
+        raise SystemExit(too_few_devices(len(devs), args.devices))
     mesh = Mesh(np.array(devs[:args.devices]), (AXIS,))
 
     model = get_workload(args.workload, n_objects=args.objects,

@@ -15,8 +15,7 @@ def cell_is_skipped(arch: str, shape_name: str) -> str | None:
     """Returns a skip reason or None."""
     if shape_name == "long_500k" and arch in FULL_ATTENTION_ONLY:
         return ("pure full-attention arch: 524k-token quadratic prefill is "
-                "not representable without sub-quadratic attention "
-                "(DESIGN.md §Arch-applicability)")
+                "not representable without sub-quadratic attention")
     return None
 
 

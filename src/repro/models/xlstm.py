@@ -1,6 +1,6 @@
 """xLSTM (arXiv:2405.04517): mLSTM + sLSTM blocks, 7:1 interleave.
 
-TPU adaptation notes (DESIGN.md §Arch-applicability):
+TPU adaptation notes:
   * mLSTM's matrix memory is computed in *chunkwise-parallel* form — the same
     matmul-rich reorganization as Mamba-2's SSD — instead of a per-step scan:
     per chunk, intra-chunk gated attention + inter-chunk state passing.  This

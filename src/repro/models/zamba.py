@@ -5,7 +5,7 @@ The shared block attends at width 2*d_model (32 heads x 128 = 4096 for the
 1.2B config) and projects back to d_model.  Each *invocation* gets its own KV
 cache slot (weights shared, caches not) — allocated per invocation, not per
 layer, so the long-context cache is ~6x smaller than a naive per-layer layout.
-LoRA-per-invocation adapters from the paper are omitted (noted in DESIGN.md).
+LoRA-per-invocation adapters from the paper are omitted.
 Layers are a python loop (heterogeneous structure; 38 small blocks keep the
 HLO manageable without scan).
 """

@@ -9,7 +9,7 @@ deliverable chunk pointers and a ``top_elem`` cursor::
 i.e. free chunks live at ``addresses[top : count)``.  We keep that discipline
 verbatim over *indices* into a preallocated node arena (placement-by-sharding
 replaces mmap+mbind — the arena array lives in the owning device's HBM by
-construction, see DESIGN.md §2).  All functions below operate on a single
+construction).  All functions below operate on a single
 object and are vmapped by the model; ``k`` is static.
 """
 from __future__ import annotations

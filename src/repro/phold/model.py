@@ -170,12 +170,11 @@ class Phold(SimModel):
 
     # -- whole-batch ProcessEvent via the Pallas kernel ------------------------
 
-    def process_batch(self, state, ts_s, seed_s, pay_s, cnt_b, lookahead,
-                      use_pallas: bool = True, interpret: bool = True):
+    def process_batch(self, state, ts_s, seed_s, pay_s, cnt_b, lookahead):
         """Apply each object's sorted epoch batch in one kernel call
         (kernels/event_apply.py — the VMEM-hot analogue of the paper's
-        cache-hot batch execution).  Drop-in for the engine's rounds loop."""
-        from ..core.api import EmittedEvents  # noqa: F401 (doc parity)
+        cache-hot batch execution).  Drop-in for the engine's rounds loop;
+        Mosaic-compiled on a TPU, interpreted on the CPU."""
         from ..core.events import EventBatch
         from ..kernels import ops
         p = self.params
@@ -184,7 +183,6 @@ class Phold(SimModel):
             payload, state["addresses"], state["top"], ts_s, seed_s, cnt_b,
             n_objects=p.n_objects, lookahead=p.lookahead, K=p.touch,
             KR=p.realloc_k, dist=p.dist, mean=p.mean_increment,
-            interpret=interpret, use_pallas=use_pallas,
             hot_objects=p.hot_objects, hot_prob=p.hot_prob)
         new_state = {"payload": jnp.swapaxes(pay2, 1, 2),
                      "addresses": addr2, "top": top2}

@@ -1,6 +1,6 @@
 """Serving: prefill + one-token decode step factories and cache shardings.
 
-Cache sharding rule (documented in DESIGN.md): the batch-sized dim shards over
+Cache sharding rule: the batch-sized dim shards over
 ("pod","data"); the largest remaining dim divisible by the "model" axis shards
 over "model" — for GQA KV caches that is the sequence dim (context-parallel
 cache) or the kv-head dim, for MLA the latent sequence, for SSM states the

@@ -376,11 +376,12 @@ def main(argv=None) -> int:
     import jax
     from jax.sharding import Mesh
     from ..core.engine import AXIS
+    from ..launch.runtime import enable_compile_cache, too_few_devices
 
+    enable_compile_cache()
     devs = jax.devices()
-    assert len(devs) >= args.devices, \
-        (f"{len(devs)} devices visible, need {args.devices} — set XLA_FLAGS="
-         f"--xla_force_host_platform_device_count={args.devices}")
+    if len(devs) < args.devices:
+        ap.error(too_few_devices(len(devs), args.devices))
     mesh = Mesh(np.array(devs[:args.devices]), (AXIS,))
 
     names = list(SWEEP) if args.configs == "all" \

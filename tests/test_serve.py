@@ -29,10 +29,7 @@ def test_serve_session_greedy_decode_is_deterministic():
 def test_cache_shardings_pick_batch_and_model_dims():
     # production-mesh geometry without devices (AbstractMesh)
     from jax.sharding import AbstractMesh
-    try:
-        mesh = AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:  # jax <= 0.4.x: shape_tuple of (name, size) pairs
-        mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cache = {"k": jax.ShapeDtypeStruct((32, 128, 4, 64), jnp.bfloat16),
              "h": jax.ShapeDtypeStruct((32, 16, 64), jnp.float32)}
     sh = cache_shardings(cache, mesh, batch_size=32)
