@@ -100,7 +100,8 @@ _CHILD = textwrap.dedent("""
                placement_slack=spec.get("placement_slack", 2.0),
                opt_window=spec.get("opt_window", 0),
                opt_stage_cap=spec.get("opt_stage_cap", 0),
-               opt_commit=spec.get("opt_commit", "device"))
+               opt_commit=spec.get("opt_commit", "device"),
+               count_rounds=True)   # the lane report below reads them
     cfg = EngineConfig(**ckw)
     eng = ParsirEngine(model, cfg, mesh=mesh)
     from repro.testing import unclean_counters
@@ -385,22 +386,24 @@ _CHILD = textwrap.dedent("""
         raise SystemExit(0)
 
     st = eng.run(eng.init(), spec.get("warm", 6))
-    base = eng.totals(st)["processed"]
-    # structural schedule cost of the warmed-up epoch, summed over devices:
-    # the dense rounds grid executes max_depth x n_local_max lanes per device
-    # whether occupied or not; packing executes ~the events present.  This is
-    # the padded-row-tax proxy a wide-SIMD accelerator would feel directly —
-    # CPU wall-clock mostly measures loop dispatch instead.
-    occ = eng.occupancy(st)
-    lanes = {"padded_lanes_epoch": int(occ["padded_lanes"].sum()),
-             "packed_lanes_epoch": int(occ["packed_lanes"].sum()),
-             "n_local_max": int(occ["n_local_max"])}
+    base = eng.totals(st)
     t0 = time.perf_counter()
     st = eng.run(st, spec["epochs"])
     st.stats.processed.block_until_ready()
     dt = time.perf_counter() - t0
     tot = eng.totals(st)
-    n = tot["processed"] - base
+    n = tot["processed"] - base["processed"]
+    # schedule cost per epoch, summed over devices, from the scheduler's own
+    # counters: the dense rounds grid executes max depth x n_local_max lanes
+    # per device whether occupied or not; packing executes about the events
+    # present.  This is the padded-row-tax proxy a wide-SIMD accelerator
+    # would feel directly; CPU wall-clock mostly measures loop dispatch.
+    # (A kernel's rounds have no fixed width: no lanes for batch-model.)
+    per_epoch = lambda k: ((tot[k] - base[k]) / spec["epochs"] if k in tot
+                           else None)
+    lanes = {"lanes_epoch": per_epoch("lanes"),
+             "rounds_epoch": per_epoch("rounds"),
+             "n_local_max": eng.placement.n_local_max}
     # structural exchange bytes per epoch: record bytes are 17B/event
     # (dst4 ts4 seed4 payload4 valid1)
     rec_b = 17

@@ -114,18 +114,6 @@ def insert(cal: Calendar, local_idx: jax.Array, epoch: jax.Array,
     return Calendar(new_ts, new_seed, new_pay, new_cnt), n_overflow
 
 
-def bucket_occupancy(cal: Calendar, epoch: jax.Array) -> jax.Array:
-    """Per-row event count of the bucket holding ``epoch`` — no drain.
-
-    The occupancy vector the width-packer's schedule is built from (round
-    ``r`` of the batch loop touches exactly the rows with ``occupancy > r``),
-    exposed separately so diagnostics (:meth:`ParsirEngine.occupancy`) and
-    tests can quantify the padded-grid vs packed work without extracting.
-    """
-    b = (epoch % cal.n_buckets).astype(jnp.int32)
-    return jax.lax.dynamic_index_in_dim(cal.cnt, b, axis=1, keepdims=False)
-
-
 def extract_sorted(cal: Calendar, epoch: jax.Array):
     """Drain the bucket for ``epoch``: per-object events sorted by (ts, seed).
 

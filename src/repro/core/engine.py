@@ -71,11 +71,11 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .api import SimModel
-from .calendar import bucket_occupancy, make_calendar, make_fallback
+from .calendar import make_calendar, make_fallback
 from .events import EventBatch
 from .pipeline import (AXIS, EngineConfig, EngineState, Stats, deliver,
                        make_spec_step, make_step, zero_stats)
-from .pipeline.base import stats_dtype
+from .pipeline.base import resolve_scheduler, stats_dtype
 from .placement import Placement, equal_placement, weighted_placement
 
 __all__ = ["AXIS", "REP_AXIS", "EngineConfig", "EngineState", "ParsirEngine",
@@ -125,6 +125,12 @@ class ParsirEngine:
         single-device and ``len(seeds) % W == 0``."""
         if mesh is None:
             mesh = Mesh(np.array(jax.devices()[:1]), (AXIS,))
+        # the stage scopes (pipeline/names.py) are op metadata, which JAX
+        # leaves out of the persistent compile cache's key by default: a
+        # cached executable would carry the scopes of whichever build first
+        # compiled the same computation.  Key the cache on them.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
         self.model, self.cfg, self.mesh = model, cfg, mesh
         D = int(np.prod(mesh.devices.shape))
         cfg.validate(D)
@@ -470,7 +476,8 @@ class ParsirEngine:
                            make_calendar(D * M, cfg.n_buckets, cfg.bucket_cap))
         fb = jax.tree.map(put, make_fallback(D * cfg.fallback_cap))
         epoch = put(jnp.zeros((D,), jnp.int32))
-        stats = jax.tree.map(lambda l: put(jnp.tile(l, D)), zero_stats())
+        stats = jax.tree.map(lambda l: put(jnp.tile(l, D)),
+                             zero_stats(cfg.count_rounds))
         b = jnp.asarray(np.asarray(self.placement.boundaries, np.int32))
         bounds = put(jnp.tile(b[None, :], (D, 1)))
         load = put(jnp.zeros((D * M,), jnp.int32))
@@ -524,13 +531,16 @@ class ParsirEngine:
         per-epoch increment of any counter is bounded by the largest static
         buffer a stage can fill: the epoch bucket (``n_local_max *
         bucket_cap``, plus claimed loans under stealing), the route buffer,
-        or the fallback list.  Every run entry point checks this bound
-        before dispatching.
+        or the fallback list.  The scheduler's ``rounds`` (under
+        ``count_rounds``) stay within the bucket per epoch run, but count a
+        speculative window's work again when it rolls back: up to
+        ``opt_window + 1`` epochs run per epoch advanced.  Every run entry
+        point checks this bound before dispatching.
         """
         cap = int(jnp.iinfo(stats_dtype()).max)
-        per_epoch = self.placement.n_local_max * self.cfg.bucket_cap
-        if self.cfg.steal:
-            per_epoch += self.cfg.claim_cap * self.cfg.bucket_cap
+        per_epoch = self._rows() * self.cfg.bucket_cap
+        if self.cfg.count_rounds:
+            per_epoch *= self.cfg.opt_window + 1
         per_epoch = max(per_epoch, self.cfg.route_cap, self.cfg.fallback_cap)
         if int(n_epochs) * per_epoch > cap:
             raise ValueError(
@@ -709,8 +719,8 @@ class ParsirEngine:
     def totals_replicated(self, state: EngineState) -> list[dict[str, int]]:
         """Per-replication Stats totals of a stacked state, in seed order."""
         sums = {k: np.asarray(l).reshape(l.shape[0], -1).sum(axis=1)
-                for k, l in state.stats._asdict().items()}
-        return [{k: int(v[r]) for k, v in sums.items()}
+                for k, l in state.stats._asdict().items() if l is not None}
+        return [self._with_lanes({k: int(v[r]) for k, v in sums.items()})
                 for r in range(state.epoch.shape[0])]
 
     def in_flight_replicated(self, state: EngineState) -> np.ndarray:
@@ -721,32 +731,30 @@ class ParsirEngine:
         return (cal + fb).astype(np.int64)
 
     def totals(self, state: EngineState) -> dict[str, int]:
-        st = jax.tree.map(lambda l: int(np.sum(np.asarray(l))), state.stats)
-        return st._asdict()
+        return self._with_lanes({k: int(np.sum(np.asarray(l)))
+                                 for k, l in state.stats._asdict().items()
+                                 if l is not None})
+
+    def _rows(self) -> int:
+        """Rows a device's scheduler runs per epoch: its local rows, plus
+        the claimed loans under stealing."""
+        rows = self.placement.n_local_max
+        return rows + self.cfg.claim_cap if self.cfg.steal else rows
+
+    def _with_lanes(self, totals: dict[str, int]) -> dict[str, int]:
+        """``totals`` with the ``lanes`` its ``rounds`` ran, where the
+        scheduler's rounds have a fixed width (summed over devices: every
+        device runs the same rows)."""
+        per = resolve_scheduler(self.cfg).lanes_per_round(self.cfg,
+                                                          self._rows())
+        if "rounds" in totals and per is not None:
+            totals["lanes"] = totals["rounds"] * per
+        return totals
 
     def in_flight(self, state: EngineState) -> int:
         cal = int(np.sum(np.asarray(state.cal.cnt)))
         fb = int(np.sum(np.asarray(state.fb.events.valid)))
         return cal + fb
-
-    def occupancy(self, state: EngineState) -> dict[str, np.ndarray | int]:
-        """Width-packing diagnostics for the *current* epoch's bucket.
-
-        Per device: live event total (``events``), max per-object batch depth
-        (``max_depth``), the dense rounds grid each device would execute
-        (``padded_lanes = max_depth × n_local_max`` — every device pays its
-        own grid, in lockstep until the collective), and the events actually
-        present (``packed_lanes``, what ``batch_impl='packed'`` processes up
-        to per-round tile rounding).  The padded-row tax is the gap.
-        """
-        M = self.placement.n_local_max
-        depth = np.asarray(
-            bucket_occupancy(state.cal, state.epoch[0])).reshape(self.D, M)
-        events = depth.sum(axis=1)
-        max_depth = depth.max(axis=1, initial=0)
-        return {"events": events, "max_depth": max_depth,
-                "padded_lanes": max_depth * M, "packed_lanes": events,
-                "n_local_max": M}
 
     def boundaries_of(self, state: EngineState) -> np.ndarray:
         """The live placement boundaries, i64[D+1] (they move under

@@ -68,6 +68,10 @@ class Stats(NamedTuple):
     speculated: jax.Array            # events processed past the safe horizon
     #                                  and committed (never counts aborted work)
     spec_commits: jax.Array          # speculation windows committed
+    rounds: jax.Array | None = None  # serial steps the scheduler ran
+    #                                  (aborted windows too); carried only
+    #                                  under EngineConfig.count_rounds, else
+    #                                  None: no leaf, no work in the loop
 
 
 def stats_dtype() -> jnp.dtype:
@@ -82,9 +86,15 @@ def stats_dtype() -> jnp.dtype:
     return jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
 
 
-def zero_stats() -> Stats:
+def zero_stats(count_rounds: bool = False) -> Stats:
     z = jnp.zeros((1,), stats_dtype())
-    return Stats(*(z,) * len(Stats._fields))
+    return Stats(*(z,) * (len(Stats._fields) - 1),
+                 rounds=z if count_rounds else None)
+
+
+def tally(count: jax.Array | None, n: jax.Array) -> jax.Array | None:
+    """``count + n`` for a counter the state may not carry (``None``)."""
+    return None if count is None else count + n
 
 
 class EngineState(NamedTuple):
@@ -109,8 +119,10 @@ def epoch_of(ts: jax.Array, epoch_len: float) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 #: a scheduler's result: (updated object pytree, flat emitted EventBatch,
-#: lookahead-violation count).
-ProcessResult = tuple[Any, EventBatch, jax.Array]
+#: lookahead-violation count, rounds).  ``rounds`` counts the serial steps
+#: the schedule ran (vmap rounds, packed tiles, kernel grid steps, single
+#: events) — the scheduler's work, beside the events it committed.
+ProcessResult = tuple[Any, EventBatch, jax.Array, jax.Array]
 
 
 class Scheduler(abc.ABC):
@@ -140,8 +152,15 @@ class Scheduler(abc.ABC):
         execution knobs a scheduler may consult (``lookahead``,
         ``pack_tile``, …).  The returned EventBatch is flat with ``valid``
         masks honored downstream — a scheduler may emit 0..``model.max_out``
-        events per processed event.
+        events per processed event.  The last result is the ``rounds`` it
+        ran (an i32 scalar, see :data:`ProcessResult`).
         """
+
+    def lanes_per_round(self, cfg: "EngineConfig", n_rows: int) -> int | None:
+        """Event slots one round executes, occupied or not, on ``n_rows``
+        local rows (static), or ``None`` where a round has no fixed width.
+        ``rounds`` times this is the scheduler's ``lanes``."""
+        return None
 
 
 class Router(abc.ABC):
@@ -211,11 +230,12 @@ class StealPolicy(abc.ABC):
                 cfg: "EngineConfig", placement: Placement, dev: jax.Array,
                 obj: Any, ts_s: jax.Array, seed_s: jax.Array,
                 pay_s: jax.Array, cnt_b: jax.Array
-                ) -> tuple[Any, EventBatch, jax.Array, jax.Array, jax.Array]:
+                ) -> tuple[Any, EventBatch, jax.Array, jax.Array, jax.Array,
+                           jax.Array]:
         """Run stage 2+3 (rebalance, then process).
 
         Returns (obj, flat emitted EventBatch, lookahead violations,
-        stolen-batch count, processed-event count).
+        stolen-batch count, processed-event count, scheduler rounds).
         """
 
 

@@ -122,6 +122,12 @@ class EngineConfig:
                             the rollback path on every device.  Only
                             the ``rollbacks`` activity meter (never a
                             clean counter) observes it.
+    ``count_rounds``        bool; default False.  Tracing: carry the
+                            scheduler's ``rounds`` in ``Stats`` (and
+                            report them, with the ``lanes`` they ran, in
+                            ``totals``).  Off, the epoch loop carries no
+                            counter and compiles to the uninstrumented
+                            program.  Observation only: same bits.
     ======================  =============================================
     """
 
@@ -157,6 +163,8 @@ class EngineConfig:
     inject_straggler_every: int = 0  # test-only: force every n-th window to
     #                                  abort (0 = off; deterministic rollback
     #                                  coverage at any device count)
+    count_rounds: bool = False       # tracing: carry the scheduler's rounds
+    #                                  in Stats (off = no counter in the loop)
 
     def __post_init__(self):
         if self.lookahead <= 0:

@@ -38,3 +38,22 @@ PLACEMENTS: tuple[str, ...] = ("equal", "weighted", "adaptive")
 #: determinism harness, not a user-facing speculation knob.)
 SPECULATION_KNOBS: tuple[str, ...] = ("opt_window", "opt_stage_cap",
                                       "opt_commit", "opt_adaptive")
+
+#: ``jax.named_scope`` names of the epoch step's stages.  Every device op of
+#: a step carries one in its ``op_name`` metadata, so a profiler trace (or
+#: the compiled HLO) attributes device time to a stage; a fused op takes its
+#: root op's scope.  The conservative step (``pipeline/step.py``) opens the
+#: first six; the speculative step (``pipeline/speculate.py``) opens them
+#: for its safe section and sub-epochs plus the last four for its own parts.
+EXTRACT = "parsir.extract"        # drain + sort the epoch's calendar bucket
+PROCESS = "parsir.process"        # steal policy + scheduler
+REBALANCE = "parsir.rebalance"    # adaptive placement: boundaries, migration
+ROUTE = "parsir.route"            # producer triage, select_send, fallback
+EXCHANGE = "parsir.exchange"      # the router's collective
+DELIVER = "parsir.deliver"        # owner-side calendar/fallback insertion
+SHADOW = "parsir.shadow"          # speculation: snapshot of window buckets
+VERDICT = "parsir.verdict"        # speculation: stragglers, the vote
+COMMIT = "parsir.commit"          # speculation: keep the window
+RESTORE = "parsir.restore"        # speculation: roll the window back
+STAGE_SCOPES: tuple[str, ...] = (EXTRACT, PROCESS, REBALANCE, ROUTE, EXCHANGE,
+                                 DELIVER, SHADOW, VERDICT, COMMIT, RESTORE)

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from ..api import SimModel
 from ..events import EventBatch
 from .base import Scheduler, register_scheduler
-from .packing import pack_slice
+from .packing import effective_tile, pack_slice
 
 
 def process_batch_rounds(model: SimModel, obj: Any, ts_s, seed_s, pay_s,
@@ -45,7 +45,8 @@ def process_batch_rounds(model: SimModel, obj: Any, ts_s, seed_s, pay_s,
     """Round r applies the r-th (ts,seed)-ordered event of every object.
 
     A plain function (not just a method) because the loan-stealing policy
-    reuses it for the claimed-batch augmented processing pass.
+    reuses it for the claimed-batch augmented processing pass.  Runs
+    ``max(cnt_b)`` rounds of ``n_rows`` lanes each, live or not.
     """
     n_rows, C = ts_s.shape
     mo = model.max_out
@@ -90,7 +91,7 @@ def process_batch_rounds(model: SimModel, obj: Any, ts_s, seed_s, pay_s,
     obj, out, lv = jax.lax.fori_loop(
         0, max_r, body, (obj, out0, jnp.int32(0)))
     flat = EventBatch(*(x.reshape(-1) for x in out))
-    return obj, flat, lv
+    return obj, flat, lv, max_r
 
 
 def process_batch_packed(model: SimModel, obj: Any, ts_s, seed_s, pay_s,
@@ -102,7 +103,8 @@ def process_batch_packed(model: SimModel, obj: Any, ts_s, seed_s, pay_s,
     per-tile gather → vmap(process_event) → scatter-back is conflict-free,
     while an object's rounds land in strictly increasing tiles (the scatter
     carries its state forward).  Identical per-event inputs in identical
-    intra-object order ⇒ bit-identical results to ``batch``.
+    intra-object order ⇒ bit-identical results to ``batch``.  Runs
+    ``n_tiles`` rounds of ``tile`` lanes each.
     """
     n_rows, C = ts_s.shape
     mo = model.max_out
@@ -116,7 +118,8 @@ def process_batch_packed(model: SimModel, obj: Any, ts_s, seed_s, pay_s,
         valid=jnp.zeros((k_pad, mo), bool),
     )
     if k_pad == 0:
-        return obj, EventBatch(*(x.reshape(-1) for x in out0)), jnp.int32(0)
+        zero = jnp.int32(0)
+        return obj, EventBatch(*(x.reshape(-1) for x in out0)), zero, zero
 
     def body(t, carry):
         obj, out, lv = carry
@@ -151,7 +154,7 @@ def process_batch_packed(model: SimModel, obj: Any, ts_s, seed_s, pay_s,
     obj, out, lv = jax.lax.fori_loop(
         0, packed.n_tiles, body, (obj, out0, jnp.int32(0)))
     flat = EventBatch(*(x.reshape(-1) for x in out))
-    return obj, flat, lv
+    return obj, flat, lv, packed.n_tiles
 
 
 @register_scheduler("batch")
@@ -161,6 +164,9 @@ class BatchRoundsScheduler(Scheduler):
     def process(self, model, cfg, obj, ts_s, seed_s, pay_s, cnt_b):
         return process_batch_rounds(model, obj, ts_s, seed_s, pay_s, cnt_b,
                                     cfg.lookahead)
+
+    def lanes_per_round(self, cfg, n_rows):
+        return n_rows
 
 
 @register_scheduler("batch-packed")
@@ -172,11 +178,15 @@ class PackedBatchScheduler(Scheduler):
         return process_batch_packed(model, obj, ts_s, seed_s, pay_s, cnt_b,
                                     cfg.lookahead, cfg.pack_tile)
 
+    def lanes_per_round(self, cfg, n_rows):
+        return effective_tile(cfg.pack_tile, n_rows)
+
 
 @register_scheduler("batch-model")
 class ModelKernelScheduler(Scheduler):
     """Whole per-object batches through the model's own kernel
-    (``batch_impl='model'``, e.g. Pallas event-apply)."""
+    (``batch_impl='model'``, e.g. Pallas event-apply).  The model's
+    ``process_batch`` reports its kernel's grid steps as the rounds."""
 
     def validate(self, model, cfg):
         if not hasattr(model, "process_batch"):
@@ -241,4 +251,8 @@ class LtfScheduler(Scheduler):
         obj, out, lv = jax.lax.fori_loop(0, total, body,
                                          (obj, out0, jnp.int32(0)))
         flat = EventBatch(*(x.reshape(-1) for x in out))
-        return obj, flat, lv
+        # one event a round: ``sum(cnt_b)`` rounds.
+        return obj, flat, lv, total
+
+    def lanes_per_round(self, cfg, n_rows):
+        return 1

@@ -96,11 +96,19 @@ branches; the loan/rebalance collectives run under replicated predicates):
      either way, so a workload with constant cross-device traffic degrades
      to conservative speed — never to livelock, and never to wrong bits.
 
+Each part runs under its name scope from :mod:`.names`: the safe section
+and the sub-epochs under the conservative step's ``parsir.extract`` /
+``process`` / ``rebalance`` / ``route`` / ``deliver``, both exchanges under
+``parsir.exchange``, then ``parsir.shadow``, ``parsir.verdict``, and
+``parsir.commit`` around the branch (``parsir.restore`` inside the abort).
+
 ``rollbacks`` / ``speculated`` / ``spec_commits`` are activity meters, not
-error counters — deliberately absent from ``CLEAN_COUNTERS``.  Every
-device increments exactly one of ``spec_commits`` / ``rollbacks`` per
-window, so per device (and divided by D across devices) their sum equals
-the fused-loop iteration count.
+error counters — deliberately absent from ``CLEAN_COUNTERS``.  So is the
+scheduler's ``rounds`` (under ``count_rounds``), which counts the work
+executed in both branches: a rolled-back window's epochs are counted again
+when they re-run.  Every device increments exactly one of
+``spec_commits`` / ``rollbacks`` per window, so per device (and divided by
+D across devices) their sum equals the fused-loop iteration count.
 """
 from __future__ import annotations
 
@@ -115,9 +123,10 @@ from ..calendar import (Fallback, extract_sorted, fallback_put, insert,
 from ..events import (EventBatch, compact, compact_mask, concat_batches,
                       empty_batch, truncate)
 from ..placement import Placement
+from . import names
 from . import rebalance, routers, schedulers, steal  # noqa: F401  (registration imports)
 from .base import (AXIS, EngineState, epoch_of, resolve_rebalance,
-                   resolve_router, resolve_scheduler, resolve_steal)
+                   resolve_router, resolve_scheduler, resolve_steal, tally)
 from .config import EngineConfig
 from .deliver import deliver
 
@@ -180,162 +189,189 @@ def make_spec_step(model: SimModel, cfg: EngineConfig, placement: Placement
                 w_eff, jnp.where(d_fire == 0, R - 1, d_fire - 1))
 
         # -- 1. safe sub-epoch e0 (committed in both branches) --------------
-        cal, ts_s, seed_s, pay_s, cnt_b = extract_sorted(state.cal, e0)
-        obj, out_flat, lv0, stolen0, proc0 = policy.process(
-            model, scheduler, cfg, pl, dev, state.obj,
-            ts_s, seed_s, pay_s, cnt_b)
+        with jax.named_scope(names.EXTRACT):
+            cal, ts_s, seed_s, pay_s, cnt_b = extract_sorted(state.cal, e0)
+        with jax.named_scope(names.PROCESS):
+            obj, out_flat, lv0, stolen0, proc0, rounds0 = \
+                policy.process(model, scheduler, cfg, pl, dev, state.obj,
+                               ts_s, seed_s, pay_s, cnt_b)
 
         if adaptive:
-            load = state.load + cnt_b
-            bounds, load, cal, obj, migrated, fired = rebalancer.rebalance(
-                cfg, placement, dev, e0, state.bounds[0], load, cal, obj)
+            with jax.named_scope(names.REBALANCE):
+                load = state.load + cnt_b
+                bounds, load, cal, obj, migrated, fired = \
+                    rebalancer.rebalance(cfg, placement, dev, e0,
+                                         state.bounds[0], load, cal, obj)
             pl = placement.with_boundaries(bounds)
         else:
             bounds, load = state.bounds[0], state.load
             migrated = fired = jnp.int32(0)
         boundaries = jnp.asarray(pl.boundaries, jnp.int32)
 
-        prod = concat_batches(out_flat, state.fb.events)
-        ep_p = epoch_of(prod.ts, cfg.epoch_len)
-        oob_p = prod.valid & ((prod.dst < 0) | (prod.dst >= O))
-        n_oob0 = jnp.sum(oob_p.astype(jnp.int32))
-        late_p = prod.valid & ~oob_p & (ep_p <= e0)
-        n_late0 = jnp.sum(late_p.astype(jnp.int32))
-        good = prod.valid & ~oob_p & ~late_p
-        local = good & (pl.owner(prod.dst) == dev)
+        with jax.named_scope(names.ROUTE):
+            prod = concat_batches(out_flat, state.fb.events)
+            ep_p = epoch_of(prod.ts, cfg.epoch_len)
+            oob_p = prod.valid & ((prod.dst < 0) | (prod.dst >= O))
+            n_oob0 = jnp.sum(oob_p.astype(jnp.int32))
+            late_p = prod.valid & ~oob_p & (ep_p <= e0)
+            n_late0 = jnp.sum(late_p.astype(jnp.int32))
+            good = prod.valid & ~oob_p & ~late_p
+            local = good & (pl.owner(prod.dst) == dev)
 
-        # remote in-horizon events ride the (must-keep) safe exchange; local
-        # events skip the collective and deliver immediately — the window's
-        # sub-epochs must see them, and slot order inside a bucket is
-        # irrelevant (extraction re-sorts by (ts, seed)).
-        remote_eligible = good & ~local & (ep_p <= e0 + N)
-        safe_buf, send, route_ovf0 = router.select_send(prod, remote_eligible,
-                                                        pl, cfg)
-        kept = compact_mask(prod, good & ~local & ~send)
-        fb = Fallback(truncate(kept, cfg.fallback_cap))
-        fb_ovf0 = jnp.sum(kept.valid[cfg.fallback_cap:].astype(jnp.int32))
-        cal, fb, cal_ovf0, fb_ovf0b, late0b, _ = deliver(
-            cal, fb, prod._replace(valid=local), e0, dev, pl, cfg,
-            init=False, replicated=False)
+            # remote in-horizon events ride the (must-keep) safe exchange;
+            # local events skip the collective and deliver immediately — the
+            # window's sub-epochs must see them, and slot order inside a
+            # bucket is irrelevant (extraction re-sorts by (ts, seed)).
+            remote_eligible = good & ~local & (ep_p <= e0 + N)
+            safe_buf, send, route_ovf0 = router.select_send(
+                prod, remote_eligible, pl, cfg)
+            kept = compact_mask(prod, good & ~local & ~send)
+            fb = Fallback(truncate(kept, cfg.fallback_cap))
+            fb_ovf0 = jnp.sum(kept.valid[cfg.fallback_cap:].astype(jnp.int32))
+        with jax.named_scope(names.DELIVER):
+            cal, fb, cal_ovf0, fb_ovf0b, late0b, _ = deliver(
+                cal, fb, prod._replace(valid=local), e0, dev, pl, cfg,
+                init=False, replicated=False)
 
         # -- 2. shadow: window buckets + object state ------------------------
-        shadow_cal = take_buckets(cal, e0 + 1, W)
+        with jax.named_scope(names.SHADOW):
+            shadow_cal = take_buckets(cal, e0 + 1, W)
         shadow_obj = obj
 
         # -- 3. speculative sub-epochs --------------------------------------
         zero = jnp.int32(0)
         staging = empty_batch(cfg.opt_stage_cap)
         # (cal, obj, staging, processed, lookahead, late, oob, cal_ovf,
-        #  stage_ovf, stolen, load) — stage_ovf feeds the violation count,
-        # the rest are Stats/load deltas applied only on commit.
+        #  stage_ovf, stolen, load, rounds) — stage_ovf feeds the violation
+        # count, rounds (the Stats counter itself: work executed counts in
+        # either branch) is None unless counted, the rest are Stats/load
+        # deltas applied only on commit.
         carry = (cal, obj, staging, zero, zero, zero, zero, zero, zero,
-                 zero, jnp.zeros_like(load))
+                 zero, jnp.zeros_like(load), tally(state.stats.rounds,
+                                                   rounds0))
 
         def sub_epoch(w):
             def run(c):
                 (cal, obj, staging, proc, lv, late, oob, covf, sovf,
-                 stl, ld) = c
+                 stl, ld, rnd) = c
                 cur = e0 + w
-                cal, ts_w, seed_w, pay_w, cnt_w = extract_sorted(cal, cur)
-                obj, out_w, lv_w, stl_w, proc_w = policy.process(
-                    model, scheduler, cfg, pl, dev, obj,
-                    ts_w, seed_w, pay_w, cnt_w)
-                ep_w = epoch_of(out_w.ts, cfg.epoch_len)
-                oob_w = out_w.valid & ((out_w.dst < 0) | (out_w.dst >= O))
-                late_w = out_w.valid & ~oob_w & (ep_w <= cur)
-                good_w = out_w.valid & ~oob_w & ~late_w
-                # local + inside the shadowed window → insert now (later
-                # sub-epochs consume it); anything else parks in staging.
-                ins = good_w & (pl.owner(out_w.dst) == dev) & (ep_w <= e0 + W)
-                lidx = jnp.clip(out_w.dst - boundaries[dev], 0,
-                                cal.n_local - 1)
-                cal, covf_w = insert(cal, lidx, ep_w, out_w.ts, out_w.seed,
-                                     out_w.payload, ins)
-                staging, sovf_w = _stage_put(
-                    staging, compact_mask(out_w, good_w & ~ins))
+                with jax.named_scope(names.EXTRACT):
+                    cal, ts_w, seed_w, pay_w, cnt_w = extract_sorted(cal, cur)
+                with jax.named_scope(names.PROCESS):
+                    obj, out_w, lv_w, stl_w, proc_w, rnd_w = \
+                        policy.process(model, scheduler, cfg, pl, dev, obj,
+                                       ts_w, seed_w, pay_w, cnt_w)
+                with jax.named_scope(names.ROUTE):
+                    ep_w = epoch_of(out_w.ts, cfg.epoch_len)
+                    oob_w = out_w.valid & ((out_w.dst < 0)
+                                           | (out_w.dst >= O))
+                    late_w = out_w.valid & ~oob_w & (ep_w <= cur)
+                    good_w = out_w.valid & ~oob_w & ~late_w
+                    # local + inside the shadowed window → insert now (later
+                    # sub-epochs consume it); anything else parks in staging.
+                    ins = (good_w & (pl.owner(out_w.dst) == dev)
+                           & (ep_w <= e0 + W))
+                with jax.named_scope(names.DELIVER):
+                    lidx = jnp.clip(out_w.dst - boundaries[dev], 0,
+                                    cal.n_local - 1)
+                    cal, covf_w = insert(cal, lidx, ep_w, out_w.ts,
+                                         out_w.seed, out_w.payload, ins)
+                with jax.named_scope(names.ROUTE):
+                    staging, sovf_w = _stage_put(
+                        staging, compact_mask(out_w, good_w & ~ins))
                 return (cal, obj, staging, proc + proc_w, lv + lv_w,
                         late + jnp.sum(late_w.astype(jnp.int32)),
                         oob + jnp.sum(oob_w.astype(jnp.int32)),
                         covf + covf_w, sovf + sovf_w, stl + stl_w,
-                        ld + cnt_w)
+                        ld + cnt_w, tally(rnd, rnd_w))
             return run
 
         for w in range(1, W + 1):
             carry = jax.lax.cond(w <= w_eff, sub_epoch(w), lambda c: c, carry)
         (cal_sp, obj_sp, staging, spec_proc, spec_lv, spec_late, spec_oob,
-         spec_covf, stage_ovf, spec_stolen, load_sp) = carry
+         spec_covf, stage_ovf, spec_stolen, load_sp, rounds) = carry
 
         # -- 4. the two exchanges (unconditional: collectives stay out of
         #       the commit/abort branches) ---------------------------------
-        routed_safe = router.exchange(safe_buf, pl, cfg)
+        with jax.named_scope(names.EXCHANGE):
+            routed_safe = router.exchange(safe_buf, pl, cfg)
 
-        ep_st = epoch_of(staging.ts, cfg.epoch_len)
-        stage_remote = staging.valid & (pl.owner(staging.dst) != dev)
-        # remote staged events up to the post-commit horizon ride the spec
-        # exchange — including window-epoch stragglers, whose *arrival* is
-        # exactly what the owner's violation count detects.
-        spec_eligible = stage_remote & (ep_st <= e0 + w_eff + N)
-        spec_buf, spec_send, spec_route_ovf = router.select_send(
-            staging, spec_eligible, pl, cfg)
-        routed_spec = router.exchange(spec_buf, pl, cfg)
+        with jax.named_scope(names.ROUTE):
+            ep_st = epoch_of(staging.ts, cfg.epoch_len)
+            stage_remote = staging.valid & (pl.owner(staging.dst) != dev)
+            # remote staged events up to the post-commit horizon ride the
+            # spec exchange — including window-epoch stragglers, whose
+            # *arrival* is exactly what the owner's violation count detects.
+            spec_eligible = stage_remote & (ep_st <= e0 + w_eff + N)
+            spec_buf, spec_send, spec_route_ovf = router.select_send(
+                staging, spec_eligible, pl, cfg)
+        with jax.named_scope(names.EXCHANGE):
+            routed_spec = router.exchange(spec_buf, pl, cfg)
 
         # -- 5. verdict: (earliest straggler epoch, violation count) --------
-        def violations(batch: EventBatch):
-            ep = epoch_of(batch.ts, cfg.epoch_len)
-            mine = (batch.valid & (batch.dst >= 0) & (batch.dst < O)
-                    & (pl.owner(batch.dst) == dev))
-            viol = mine & (ep <= e0 + w_eff)
-            return (jnp.sum(viol.astype(jnp.int32)),
-                    jnp.min(jnp.where(viol, ep, NO_STRAGGLER)))
+        with jax.named_scope(names.VERDICT):
+            def violations(batch: EventBatch):
+                ep = epoch_of(batch.ts, cfg.epoch_len)
+                mine = (batch.valid & (batch.dst >= 0) & (batch.dst < O)
+                        & (pl.owner(batch.dst) == dev))
+                viol = mine & (ep <= e0 + w_eff)
+                return (jnp.sum(viol.astype(jnp.int32)),
+                        jnp.min(jnp.where(viol, ep, NO_STRAGGLER)))
 
-        # a staged/spec-routed event the buffers couldn't carry must abort
-        # *its sender*: parking it for a later epoch could make it LATE
-        # (dropped), and a conservative engine never drops — the abort
-        # re-emits it.  Its horizon contribution is e0+1 (conservative: the
-        # lost events themselves carry epochs >= e0+2).
-        cnt_sf, m_sf = violations(routed_safe)
-        cnt_sp, m_sp = violations(routed_spec)
-        v_local = cnt_sf + cnt_sp + stage_ovf + spec_route_ovf
-        m_local = jnp.minimum(m_sf, m_sp)
-        m_local = jnp.where(stage_ovf + spec_route_ovf > 0,
-                            jnp.minimum(m_local, e0 + 1), m_local)
+            # a staged/spec-routed event the buffers couldn't carry must
+            # abort *its sender*: parking it for a later epoch could make it
+            # LATE (dropped), and a conservative engine never drops — the
+            # abort re-emits it.  Its horizon contribution is e0+1
+            # (conservative: the lost events themselves carry epochs
+            # >= e0+2).
+            cnt_sf, m_sf = violations(routed_safe)
+            cnt_sp, m_sp = violations(routed_spec)
+            v_local = cnt_sf + cnt_sp + stage_ovf + spec_route_ovf
+            m_local = jnp.minimum(m_sf, m_sp)
+            m_local = jnp.where(stage_ovf + spec_route_ovf > 0,
+                                jnp.minimum(m_local, e0 + 1), m_local)
 
-        if inject > 0:
-            # deterministic straggler injection: every inject-th window is
-            # forced down the abort path on every device (the count below is
-            # replicated in value — each device resolves one verdict per
-            # window).  Schedule-only: abort IS the conservative path.
-            windows = state.stats.spec_commits[0] + state.stats.rollbacks[0]
-            fire_inj = (windows % inject == inject - 1) & (w_eff > 0)
-            v_local = v_local + jnp.where(fire_inj, 1, 0).astype(jnp.int32)
-            m_local = jnp.where(fire_inj, jnp.minimum(m_local, e0 + 1),
-                                m_local)
+            if inject > 0:
+                # deterministic straggler injection: every inject-th window
+                # is forced down the abort path on every device (the count
+                # below is replicated in value — each device resolves one
+                # verdict per window).  Schedule-only: abort IS the
+                # conservative path.
+                windows = (state.stats.spec_commits[0]
+                           + state.stats.rollbacks[0])
+                fire_inj = (windows % inject == inject - 1) & (w_eff > 0)
+                v_local = v_local + jnp.where(fire_inj, 1, 0).astype(
+                    jnp.int32)
+                m_local = jnp.where(fire_inj, jnp.minimum(m_local, e0 + 1),
+                                    m_local)
 
-        g = jax.lax.all_gather(jnp.stack([m_local, v_local]), AXIS)  # [D, 2]
-        m_global = jnp.min(g[:, 0])
-        all_commit = m_global == NO_STRAGGLER
-        if per_device:
-            keep_vec = (g[:, 1] == 0) & (e0 + w_eff <= m_global)
-            keep_d = (v_local == 0) & (e0 + w_eff <= m_global)
-        else:
-            keep_vec = jnp.broadcast_to(all_commit, (g.shape[0],))
-            keep_d = all_commit
+            g = jax.lax.all_gather(jnp.stack([m_local, v_local]),
+                                   AXIS)                           # [D, 2]
+            m_global = jnp.min(g[:, 0])
+            all_commit = m_global == NO_STRAGGLER
+            if per_device:
+                keep_vec = (g[:, 1] == 0) & (e0 + w_eff <= m_global)
+                keep_d = (v_local == 0) & (e0 + w_eff <= m_global)
+            else:
+                keep_vec = jnp.broadcast_to(all_commit, (g.shape[0],))
+                keep_d = all_commit
 
-        # replicated across devices even when verdicts differ: a mixed
-        # verdict advances by 1 (keepers re-walk committed epochs as empty
-        # no-ops) and keepers deliver at cur = e0 — their arrivals are all
-        # past the window (v_local == 0), so nothing lands late and
-        # beyond-horizon arrivals park in the fallback.
-        e_next = jnp.where(all_commit, e0 + w_eff + 1, e0 + 1)
-        cur_c = jnp.where(all_commit, e0 + w_eff, e0)
+            # replicated across devices even when verdicts differ: a mixed
+            # verdict advances by 1 (keepers re-walk committed epochs as
+            # empty no-ops) and keepers deliver at cur = e0 — their arrivals
+            # are all past the window (v_local == 0), so nothing lands late
+            # and beyond-horizon arrivals park in the fallback.
+            e_next = jnp.where(all_commit, e0 + w_eff + 1, e0 + 1)
+            cur_c = jnp.where(all_commit, e0 + w_eff, e0)
 
-        # speculative arrivals filtered by the *sender's* verdict: an
-        # aborting sender re-executes and re-sends (drop round 1 here); a
-        # keeper never re-sends (deliver round 1, even into an abort).
-        spec_arrivals = routed_spec._replace(
-            valid=routed_spec.valid & keep_vec[senders])
+            # speculative arrivals filtered by the *sender's* verdict: an
+            # aborting sender re-executes and re-sends (drop round 1 here);
+            # a keeper never re-sends (deliver round 1, even into an abort).
+            spec_arrivals = routed_spec._replace(
+                valid=routed_spec.valid & keep_vec[senders])
 
         # -- 6. commit or roll back (local ops only) ------------------------
+        @jax.named_scope(names.COMMIT)
         def commit(_):
             c, f, co1, fo1, l1, _ = deliver(
                 cal_sp, fb, routed_safe, cur_c, dev, pl, cfg, init=False,
@@ -359,6 +395,7 @@ def make_spec_step(model: SimModel, cfg: EngineConfig, placement: Placement
                       spec_stolen, load_sp)
             return c, f, obj_sp, deltas
 
+        @jax.named_scope(names.RESTORE)
         def abort(_):
             c = put_buckets(cal_sp, e0 + 1, shadow_cal)
             c, f, co1, fo1, l1, _ = deliver(
@@ -374,8 +411,9 @@ def make_spec_step(model: SimModel, cfg: EngineConfig, placement: Placement
                       jnp.zeros_like(load_sp))
             return c, f, shadow_obj, deltas
 
-        cal_f, fb_f, obj_f, deltas = jax.lax.cond(
-            keep_d, commit, abort, None)
+        with jax.named_scope(names.COMMIT):
+            cal_f, fb_f, obj_f, deltas = jax.lax.cond(
+                keep_d, commit, abort, None)
         (d_proc, d_lv, d_late, d_oob, d_covf, d_fovf, d_l2,
          d_rb, d_cm, d_spec, d_stolen, d_load) = deltas
 
@@ -395,6 +433,7 @@ def make_spec_step(model: SimModel, cfg: EngineConfig, placement: Placement
             rollbacks=st.rollbacks + d_rb,
             speculated=st.speculated + d_spec,
             spec_commits=st.spec_commits + d_cm,
+            rounds=rounds,
         )
         return EngineState(cal_f, fb_f, obj_f,
                            jnp.reshape(e_next, state.epoch.shape), stats,

@@ -45,9 +45,9 @@ class NoSteal(StealPolicy):
 
     def process(self, model, scheduler, cfg, placement, dev, obj, ts_s,
                 seed_s, pay_s, cnt_b):
-        obj, out_flat, lv = scheduler.process(model, cfg, obj, ts_s, seed_s,
-                                              pay_s, cnt_b)
-        return obj, out_flat, lv, jnp.int32(0), jnp.sum(cnt_b)
+        obj, out_flat, lv, rounds = scheduler.process(
+            model, cfg, obj, ts_s, seed_s, pay_s, cnt_b)
+        return obj, out_flat, lv, jnp.int32(0), jnp.sum(cnt_b), rounds
 
 
 @register_steal_policy("loan")
@@ -107,7 +107,9 @@ class LoanSteal(StealPolicy):
         pay_aug = jnp.concatenate([pay_s, cl_pay], axis=0)
         cnt_aug = jnp.concatenate([cnt_b, cl_cnt], axis=0)
 
-        obj_aug, out_flat, lv = scheduler.process(
+        # the augmented pass is the device's only pass: its rounds cover
+        # the local rows and the claimed loans together.
+        obj_aug, out_flat, lv, rounds = scheduler.process(
             model, cfg, obj_aug, ts_aug, seed_aug, pay_aug, cnt_aug)
         obj = jax.tree.map(lambda l: l[:n_local], obj_aug)
         ret_state = jax.tree.map(lambda l: l[n_local:], obj_aug)
@@ -123,4 +125,5 @@ class LoanSteal(StealPolicy):
         obj = steal_mod.scatter_rows(obj, lidx, rstate, rmine)
 
         proc_count = jnp.sum(cnt_b) + jnp.sum(cl_cnt)
-        return obj, out_flat, lv, jnp.sum(cvalid.astype(jnp.int32)), proc_count
+        return (obj, out_flat, lv, jnp.sum(cvalid.astype(jnp.int32)),
+                proc_count, rounds)

@@ -2,6 +2,13 @@
 
     extract → steal → process → rebalance → route → deliver  (+ stats)
 
+Each stage runs under its ``jax.named_scope`` from
+:mod:`repro.core.pipeline.names` (``parsir.extract``, ``parsir.process`` —
+steal policy and scheduler —, ``parsir.rebalance``, ``parsir.route``,
+``parsir.exchange``, ``parsir.deliver``): op metadata only, so the compiled
+computation is unchanged while a profiler trace names each stage's device
+time.
+
 Stage behavior lives behind the :mod:`repro.core.pipeline.base` interfaces;
 :func:`make_step` resolves the configured Scheduler / Router / StealPolicy /
 RebalancePolicy once, runs their fail-fast validation, and returns the
@@ -33,9 +40,10 @@ from ..api import SimModel
 from ..calendar import Fallback, extract_sorted
 from ..events import compact_mask, concat_batches, truncate
 from ..placement import Placement
+from . import names
 from . import rebalance, routers, schedulers, steal  # noqa: F401  (registration imports)
 from .base import (AXIS, EngineState, epoch_of, resolve_rebalance,
-                   resolve_router, resolve_scheduler, resolve_steal)
+                   resolve_router, resolve_scheduler, resolve_steal, tally)
 from .config import EngineConfig
 from .deliver import deliver
 
@@ -60,21 +68,25 @@ def make_step(model: SimModel, cfg: EngineConfig, placement: Placement
         pl = placement.with_boundaries(state.bounds[0])
 
         # 1. extract — drain the calendar bucket of the current epoch.
-        cal, ts_s, seed_s, pay_s, cnt_b = extract_sorted(state.cal, cur)
+        with jax.named_scope(names.EXTRACT):
+            cal, ts_s, seed_s, pay_s, cnt_b = extract_sorted(state.cal, cur)
 
         # 2.+3. steal + process — the policy runs the scheduler (possibly on
         # loan-augmented batches) and reports emitted events + counts.
-        obj, out_flat, lv, stolen, proc_count = policy.process(
-            model, scheduler, cfg, pl, dev, state.obj,
-            ts_s, seed_s, pay_s, cnt_b)
+        with jax.named_scope(names.PROCESS):
+            obj, out_flat, lv, stolen, proc_count, rounds = \
+                policy.process(model, scheduler, cfg, pl, dev, state.obj,
+                               ts_s, seed_s, pay_s, cnt_b)
 
         # 3b. rebalance — adaptive placement moves the boundaries and
         # migrates object rows at epoch boundaries; everything downstream
         # (routing, delivery) sees the new cuts.
         if adaptive:
-            load = state.load + cnt_b
-            bounds, load, cal, obj, migrated, fired = rebalancer.rebalance(
-                cfg, placement, dev, cur, state.bounds[0], load, cal, obj)
+            with jax.named_scope(names.REBALANCE):
+                load = state.load + cnt_b
+                bounds, load, cal, obj, migrated, fired = \
+                    rebalancer.rebalance(cfg, placement, dev, cur,
+                                         state.bounds[0], load, cal, obj)
             pl = placement.with_boundaries(bounds)
         else:
             bounds, load = state.bounds[0], state.load
@@ -82,30 +94,34 @@ def make_step(model: SimModel, cfg: EngineConfig, placement: Placement
 
         # 4. route — producer-side triage (fresh events + fallback entries),
         # selection against the route capacity, then the exchange collective.
-        prod = concat_batches(out_flat, state.fb.events)
-        epochs = epoch_of(prod.ts, cfg.epoch_len)
-        oob = prod.valid & ((prod.dst < 0) | (prod.dst >= O))
-        n_oob = jnp.sum(oob.astype(jnp.int32))
-        eligible = prod.valid & ~oob & (epochs >= cur + 1) & (epochs <= cur + N)
-        late_prod = prod.valid & ~oob & (epochs <= cur)
-        n_late_prod = jnp.sum(late_prod.astype(jnp.int32))
+        with jax.named_scope(names.ROUTE):
+            prod = concat_batches(out_flat, state.fb.events)
+            epochs = epoch_of(prod.ts, cfg.epoch_len)
+            oob = prod.valid & ((prod.dst < 0) | (prod.dst >= O))
+            n_oob = jnp.sum(oob.astype(jnp.int32))
+            eligible = (prod.valid & ~oob & (epochs >= cur + 1)
+                        & (epochs <= cur + N))
+            late_prod = prod.valid & ~oob & (epochs <= cur)
+            n_late_prod = jnp.sum(late_prod.astype(jnp.int32))
 
-        route_buf, send, route_ovf = router.select_send(prod, eligible,
-                                                        pl, cfg)
+            route_buf, send, route_ovf = router.select_send(prod, eligible,
+                                                            pl, cfg)
 
-        keep = prod.valid & ~send & ~late_prod & ~oob
-        kept = compact_mask(prod, keep)
-        fb = Fallback(truncate(kept, cfg.fallback_cap))
-        fb_ovf = jnp.sum(kept.valid[cfg.fallback_cap:].astype(jnp.int32))
+            keep = prod.valid & ~send & ~late_prod & ~oob
+            kept = compact_mask(prod, keep)
+            fb = Fallback(truncate(kept, cfg.fallback_cap))
+            fb_ovf = jnp.sum(kept.valid[cfg.fallback_cap:].astype(jnp.int32))
 
-        routed = router.exchange(route_buf, pl, cfg)
+        with jax.named_scope(names.EXCHANGE):
+            routed = router.exchange(route_buf, pl, cfg)
 
         # 5. deliver — owners insert into calendar buckets / fallback.  The
         # router declares its output topology: a broadcast batch is counted
         # once globally, a per-device a2a slice is counted where it lands.
-        cal, fb, cal_ovf, fb_ovf2, late2, oob2 = deliver(
-            cal, fb, routed, cur, dev, pl, cfg, init=False,
-            replicated=router.replicated)
+        with jax.named_scope(names.DELIVER):
+            cal, fb, cal_ovf, fb_ovf2, late2, oob2 = deliver(
+                cal, fb, routed, cur, dev, pl, cfg, init=False,
+                replicated=router.replicated)
 
         st = state.stats
         # the conservative step never speculates: rollbacks / speculated /
@@ -122,6 +138,7 @@ def make_step(model: SimModel, cfg: EngineConfig, placement: Placement
             oob_events=st.oob_events + n_oob + oob2,
             rebalances=st.rebalances + fired,
             migrated=st.migrated + migrated,
+            rounds=tally(st.rounds, rounds),
         )
         return EngineState(cal, fb, obj, state.epoch + 1, stats,
                            bounds[None, :], load)

@@ -3,7 +3,8 @@
   PYTHONPATH=src python -m repro.launch.simulate --workload phold \\
       --epochs 100 [--devices 2] [--scheduler ltf] [--route a2a] \\
       [--batch-impl packed] [--placement adaptive --rebalance-every 4] \\
-      [--steal] [--drain] [--model-kw n_channels=2] [--verify]
+      [--steal] [--drain] [--model-kw n_channels=2] [--verify] \\
+      [--profile DIR]
 
 Every choice-typed flag is driven by the live registries — the workload zoo
 (:mod:`repro.workloads.registry`) and the pipeline stage names
@@ -20,10 +21,16 @@ meaningless) and the process exits nonzero via the shared
 (:meth:`ParsirEngine.run_until_drained` bounded by ``--epochs``) instead of
 a fixed horizon; ``--verify`` cross-checks the final object state bit-exactly
 against the sequential oracle for any workload under ``--dist dyadic``.
+``--profile DIR`` writes a profiler trace of the timed run to ``DIR``: open
+it in TensorBoard or Perfetto, where every device op names its epoch-step
+stage (``parsir.extract``, ``parsir.process``, ... — see
+``docs/architecture.md``).  It also turns on ``count_rounds``, so the stats
+line reports the scheduler's ``rounds`` and ``lanes``.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 
@@ -97,6 +104,11 @@ def main():
     ap.add_argument("--drain", action="store_true",
                     help="run to empty as ONE fused on-device dispatch "
                          "(run_until_drained, bounded by --epochs)")
+    ap.add_argument("--profile", metavar="DIR", default=None,
+                    help="write a profiler trace of the timed run to DIR "
+                         "(TensorBoard/Perfetto; device ops carry their "
+                         "parsir.* stage scopes) and count the scheduler's "
+                         "rounds and lanes")
     ap.add_argument("--verify", action="store_true",
                     help="cross-check final object state against the "
                          "sequential oracle (dyadic dist only)")
@@ -130,7 +142,8 @@ def main():
         placement=args.placement, rebalance_every=args.rebalance_every,
         migrate_cap=args.migrate_cap, placement_slack=args.placement_slack,
         opt_window=args.opt_window, opt_stage_cap=args.opt_stage_cap,
-        opt_commit=args.opt_commit, opt_adaptive=args.opt_adaptive)
+        opt_commit=args.opt_commit, opt_adaptive=args.opt_adaptive,
+        count_rounds=bool(args.profile))
     eng = ParsirEngine(model, cfg, mesh=mesh)
 
     st = eng.init()
@@ -139,11 +152,13 @@ def main():
     st = (eng.run_until_drained(st, 0) if args.drain else eng.run(st, 0))
     base = eng.totals(st)["processed"]
 
-    t0 = time.perf_counter()
-    st = (eng.run_until_drained(st, args.epochs) if args.drain
-          else eng.run(st, args.epochs))
-    st.stats.processed.block_until_ready()
-    dt = time.perf_counter() - t0
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        t0 = time.perf_counter()
+        st = (eng.run_until_drained(st, args.epochs) if args.drain
+              else eng.run(st, args.epochs))
+        st.stats.processed.block_until_ready()
+        dt = time.perf_counter() - t0
 
     tot = eng.totals(st)
     epochs_run = int(np.asarray(st.epoch)[0])

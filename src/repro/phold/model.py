@@ -174,9 +174,11 @@ class Phold(SimModel):
         """Apply each object's sorted epoch batch in one kernel call
         (kernels/event_apply.py — the VMEM-hot analogue of the paper's
         cache-hot batch execution).  Drop-in for the engine's rounds loop;
-        Mosaic-compiled on a TPU, interpreted on the CPU."""
+        Mosaic-compiled on a TPU, interpreted on the CPU.  Its rounds are
+        the kernel's grid steps, one per ``OBJ_BLOCK`` objects."""
         from ..core.events import EventBatch
         from ..kernels import ops
+        from ..kernels.event_apply import OBJ_BLOCK
         p = self.params
         payload = jnp.swapaxes(state["payload"], 1, 2)   # [n,S,LN] → [n,LN,S]
         (pay2, addr2, top2, odst, ots, oseed, opay, ovalid) = ops.event_apply(
@@ -192,7 +194,8 @@ class Phold(SimModel):
                          valid=valid.reshape(-1))
         lv = jnp.sum((valid & (ots < ts_s + jnp.float32(lookahead))
                       ).astype(jnp.int32))
-        return new_state, out, lv
+        grid = jnp.int32(-(-ts_s.shape[0] // OBJ_BLOCK))
+        return new_state, out, lv, grid
 
     # -- numpy mirror (sequential oracle) --------------------------------------
 

@@ -22,8 +22,8 @@ from __future__ import annotations
 from typing import Mapping
 
 #: every Stats counter that must be zero after a healthy run.  ``processed``
-#: / ``stolen`` / ``rebalances`` / ``migrated`` are activity meters, not
-#: error counters, and are deliberately absent.
+#: / ``stolen`` / ``rebalances`` / ``migrated`` / ``rounds`` are activity
+#: meters, not error counters, and are deliberately absent.
 CLEAN_COUNTERS: tuple[str, ...] = (
     "cal_overflow",          # calendar bucket capacity exceeded
     "fb_overflow",           # fallback spill — events counted then DROPPED
