@@ -132,23 +132,28 @@ class Phold(SimModel):
         S, K, KR = p.state_nodes, p.touch, p.realloc_k
         seed = seed.astype(jnp.uint32)
 
-        # contiguous touch window (no wraparound) — keeps the hot region a
-        # single dynamic slice, which is what the Pallas event_apply kernel
-        # loads into VMEM (see kernels/event_apply.py).
+        # contiguous touch window [start, start + K) (no wraparound).  Both
+        # payload writes are masked dense updates over the object's [S, LANES]
+        # rows (node iota compare + select), as in the Pallas event_apply
+        # kernel: vmapped over every object of a round, an indexed
+        # .at[idx].set would become one chip scatter of n_objects x K rows.
         start = (ev.fold(seed, 0) % jnp.uint32(S - K + 1)).astype(jnp.int32)
         idx = start + jnp.arange(K, dtype=jnp.int32)
         del payload  # PHOLD's handler keys everything off the event seed
         delta = ev.dyadic10(ev.fold(seed, 5))
-        rows = state["payload"][idx]                       # [K, LANES] gather
-        state_payload = state["payload"].at[idx].set(
-            rows * jnp.float32(0.5) + delta)
+        node = jnp.arange(S, dtype=jnp.int32)[:, None]     # [S, 1]
+        win = (node >= start) & (node < start + K)
+        state_payload = jnp.where(
+            win, state["payload"] * jnp.float32(0.5) + delta, state["payload"])
 
         a = ar.Arena(state["addresses"], state["top"])
         a = ar.free_k(a, idx[:KR])
         a, got = ar.alloc_k(a, KR)
         initval = ev.dyadic10(ev.fold(seed, 6))
-        state_payload = state_payload.at[got].set(
-            jnp.full((KR, p.lanes), 0.0, jnp.float32) + initval)
+        # after the window, so a reallocated node inside it ends as initval.
+        hit = jnp.any(node == got[None, :], axis=1, keepdims=True)
+        state_payload = jnp.where(hit, jnp.float32(0.0) + initval,
+                                  state_payload)
 
         dst = (ev.fold(seed, 1) % jnp.uint32(p.n_objects)).astype(jnp.int32)
         if p.hot_objects and p.hot_prob:

@@ -8,6 +8,9 @@ chip's 16 GB.  Nothing runs here; results and times come from the chip.
 The topology is described inside a module-scoped fixture (never at import):
 only one process at a time may load the TPU library.
 """
+import math
+import re
+
 import numpy as np
 import pytest
 
@@ -42,6 +45,29 @@ def no_persistent_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", before)
     cc.reset_cache()
+
+
+_DEF = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = \w+\[([\d,]*)\]", re.M)
+_INDEXED = re.compile(
+    r"\b(scatter|gather)\((?:\w+\[([\d,]*)\]\S*\s+)?%?([\w.\-]+)")
+
+
+def _elems(dims: str) -> int:
+    return math.prod(int(d) for d in dims.split(",") if d)
+
+
+def payload_index_ops(hlo: str, n_elems: int) -> list[str]:
+    """The scatters and gathers in HLO text whose indexed operand holds
+    ``n_elems`` elements, under any layout or relayout (``[n,S,LANES]``,
+    ``[LANES,n*S]`` …).  Operand shapes are read inline where the text
+    prints them, else from the operand's definition."""
+    shape = {m.group(1): m.group(2) for m in _DEF.finditer(hlo)}
+    found = []
+    for m in _INDEXED.finditer(hlo):
+        dims = m.group(2) if m.group(2) is not None else shape.get(m.group(3))
+        if dims is not None and _elems(dims) == n_elems:
+            found.append(m.group(0))
+    return found
 
 
 def _fits(compiled):
@@ -94,3 +120,33 @@ def test_phold_table2_drain_compiles_for_v5e(topo):
     bound = jax.ShapeDtypeStruct((), jnp.int32,
                                  sharding=NamedSharding(mesh, P()))
     _fits(eng._drain_sm.lower(state, bound).compile())
+
+
+def test_phold_table2_run_indexes_no_payload_row(topo):
+    # The rounds loop's handler writes PHOLD's touch window and reallocated
+    # nodes as masked dense updates: the compiled Table II `run` (the
+    # benchmark's program) holds no scatter or gather over the payload.
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.engine import AXIS, EngineConfig, ParsirEngine
+    from repro.workloads.registry import get_workload
+
+    model = get_workload("phold")          # PholdParams defaults: Table II
+    p = model.params
+    cfg = EngineConfig(lookahead=p.lookahead, n_buckets=16, bucket_cap=256,
+                       route_cap=8192, fallback_cap=8192, route="allgather",
+                       scheduler="batch", batch_impl="rounds")
+    shapes = jax.eval_shape(ParsirEngine(model, cfg).init)
+    mesh = Mesh(np.array(topo.devices[:1]), (AXIS,))
+    eng = ParsirEngine(model, cfg, mesh=mesh)
+    state = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                       sharding=eng._sharding), shapes)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P()))
+    hlo = eng._run_sm.lower(state, n).compile().as_text()
+    nodes = p.n_objects * p.state_nodes
+    assert payload_index_ops(hlo, nodes * p.lanes) == []
+    # the parser does see indexed ops: the allocator's stack keeps its own.
+    assert payload_index_ops(hlo, nodes)
