@@ -30,7 +30,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .events import EventBatch
+from .events import EventBatch, concat_batches, take_first
 
 
 class Calendar(NamedTuple):
@@ -252,11 +252,8 @@ def make_fallback(cap: int) -> Fallback:
 def fallback_put(fb: Fallback, new: EventBatch):
     """Append valid events of ``new`` into free slots of the fallback buffer.
 
-    Returns (fallback, n_overflow).  Compaction keeps live events in front.
+    Returns (fallback, n_overflow).  Live events stay in front, in order.
     """
-    from .events import compact, concat_batches
-    merged = compact(concat_batches(fb.events, new))
-    cap = fb.cap
-    keep = EventBatch(*(x[..., :cap] for x in merged))
-    spill = merged.valid[..., cap:]
-    return Fallback(keep), jnp.sum(spill.astype(jnp.int32))
+    merged = concat_batches(fb.events, new)
+    keep, spill = take_first(merged, merged.valid, fb.cap)
+    return Fallback(keep), spill

@@ -58,22 +58,47 @@ def concat_batches(a: EventBatch, b: EventBatch) -> EventBatch:
     return EventBatch(*(jnp.concatenate([x, y], axis=-1) for x, y in zip(a, b)))
 
 
-def compact(batch: EventBatch) -> EventBatch:
-    """Stable-move valid events to the front of the batch."""
-    return compact_mask(batch, batch.valid)
+#: slots per row of :func:`take_first`'s two-level search: one vector
+#: register's lanes.
+SELECT_ROW = 128
 
 
-def compact_mask(batch: EventBatch, mask: jax.Array) -> EventBatch:
-    """Keep only ``mask`` events (stable order, moved to the front)."""
-    # sort by !mask (False<True), stable → selected entries first, order kept.
-    order = jnp.argsort(~mask, axis=-1, stable=True)
-    take = lambda x: jnp.take_along_axis(x, order, axis=-1)
-    return EventBatch(take(batch.dst), take(batch.ts), take(batch.seed),
-                      take(batch.payload), take(mask & batch.valid))
+def take_first(batch: EventBatch, mask: jax.Array, cap: int
+               ) -> tuple[EventBatch, jax.Array]:
+    """The first ``cap`` events that ``mask`` selects, in batch order, and
+    how many selected events did not fit (the overflow past ``cap``).
 
+    ``mask`` selects valid events only (``mask`` implies ``batch.valid``).
+    Output slot ``j`` holds the ``(j+1)``-th selected event, found in two
+    levels over rows of :data:`SELECT_ROW` slots: the prefix sum of the row
+    counts gives its row (slot ``j`` against every row's end), and the
+    prefix sum inside that row its place.  Nothing is sorted; each field is
+    gathered as ``cap`` rows and the event picked out of its row by a
+    one-hot sum of its bits, which is exact.  Slots past the count hold
+    :func:`empty_batch`'s fill.  One-dimensional; vmap it over leading axes.
+    """
+    pad = -mask.shape[-1] % SELECT_ROW
+    rows = lambda x: jnp.pad(x, (0, pad)).reshape(-1, SELECT_ROW)
+    in_row = jnp.cumsum(rows(mask.astype(jnp.int32)), axis=1)
+    count = in_row[:, -1]
+    ends = jnp.cumsum(count)
+    n = ends[-1]
+    j = jnp.arange(cap, dtype=jnp.int32)
+    before = ends[None, :] <= j[:, None]          # rows wholly before slot j
+    r = jnp.minimum(jnp.sum(before, axis=1), count.shape[0] - 1)
+    k = j - jnp.sum(jnp.where(before, count[None, :], 0), axis=1)
+    at = jnp.minimum(jnp.sum(in_row[r] <= k[:, None], axis=1), SELECT_ROW - 1)
+    hit = jnp.arange(SELECT_ROW, dtype=jnp.int32)[None, :] == at[:, None]
+    ok = j < n
 
-def truncate(batch: EventBatch, cap: int) -> EventBatch:
-    return EventBatch(*(x[..., :cap] for x in batch))
+    def take(x, fill):
+        bits = x.view(jnp.uint32) if x.dtype == jnp.float32 else x
+        v = jnp.sum(jnp.where(hit, rows(bits)[r], 0), axis=1, dtype=bits.dtype)
+        return jnp.where(ok, v.view(x.dtype), fill)
+
+    out = EventBatch(*(take(x, f) for x, f in zip(batch[:4],
+                                                   empty_batch(cap)[:4])), ok)
+    return out, n - jnp.minimum(n, cap)
 
 
 # ---------------------------------------------------------------------------

@@ -18,7 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..events import EventBatch, compact_mask, truncate
+from ..events import EventBatch, take_first
 from .base import AXIS, Router, register_router
 
 
@@ -26,8 +26,7 @@ def _select_send_global(prod: EventBatch, eligible, cfg):
     """First-come selection: the first route_cap eligible events are sent."""
     rank = jnp.cumsum(eligible.astype(jnp.int32)) - 1
     send = eligible & (rank < cfg.route_cap)
-    ovf = jnp.sum((eligible & ~send).astype(jnp.int32))
-    buf = truncate(compact_mask(prod, send), cfg.route_cap)
+    buf, ovf = take_first(prod, eligible, cfg.route_cap)
     return buf, send, ovf
 
 

@@ -120,8 +120,7 @@ import jax.numpy as jnp
 from ..api import SimModel
 from ..calendar import (Fallback, extract_sorted, fallback_put, insert,
                         put_buckets, take_buckets)
-from ..events import (EventBatch, compact, compact_mask, concat_batches,
-                      empty_batch, truncate)
+from ..events import EventBatch, concat_batches, empty_batch, take_first
 from ..placement import Placement
 from . import names
 from . import rebalance, routers, schedulers, steal  # noqa: F401  (registration imports)
@@ -132,19 +131,6 @@ from .deliver import deliver
 
 #: "no in-window arrival" marker for the per-device earliest-straggler epoch.
 NO_STRAGGLER = jnp.iinfo(jnp.int32).max
-
-
-def _stage_put(staging: EventBatch, new: EventBatch):
-    """Append valid events of ``new`` into the staging buffer (compacting).
-
-    Same discipline as :func:`repro.core.calendar.fallback_put`, on a bare
-    EventBatch: overflow is *counted* — the step turns it into an abort, so
-    a too-small ``opt_stage_cap`` costs speed, never events.
-    """
-    merged = compact(concat_batches(staging, new))
-    cap = staging.capacity
-    spill = jnp.sum(merged.valid[..., cap:].astype(jnp.int32))
-    return truncate(merged, cap), spill
 
 
 def make_spec_step(model: SimModel, cfg: EngineConfig, placement: Placement
@@ -225,9 +211,9 @@ def make_spec_step(model: SimModel, cfg: EngineConfig, placement: Placement
             remote_eligible = good & ~local & (ep_p <= e0 + N)
             safe_buf, send, route_ovf0 = router.select_send(
                 prod, remote_eligible, pl, cfg)
-            kept = compact_mask(prod, good & ~local & ~send)
-            fb = Fallback(truncate(kept, cfg.fallback_cap))
-            fb_ovf0 = jnp.sum(kept.valid[cfg.fallback_cap:].astype(jnp.int32))
+            kept, fb_ovf0 = take_first(prod, good & ~local & ~send,
+                                       cfg.fallback_cap)
+            fb = Fallback(kept)
         with jax.named_scope(names.DELIVER):
             cal, fb, cal_ovf0, fb_ovf0b, late0b, _ = deliver(
                 cal, fb, prod._replace(valid=local), e0, dev, pl, cfg,
@@ -276,9 +262,14 @@ def make_spec_step(model: SimModel, cfg: EngineConfig, placement: Placement
                                     cal.n_local - 1)
                     cal, covf_w = insert(cal, lidx, ep_w, out_w.ts,
                                          out_w.seed, out_w.payload, ins)
+                # staging overflow is counted: the step turns it into an
+                # abort, so a too-small opt_stage_cap costs speed, never
+                # events.
                 with jax.named_scope(names.ROUTE):
-                    staging, sovf_w = _stage_put(
-                        staging, compact_mask(out_w, good_w & ~ins))
+                    staged, sovf_w = fallback_put(
+                        Fallback(staging),
+                        out_w._replace(valid=good_w & ~ins))
+                    staging = staged.events
                 return (cal, obj, staging, proc + proc_w, lv + lv_w,
                         late + jnp.sum(late_w.astype(jnp.int32)),
                         oob + jnp.sum(oob_w.astype(jnp.int32)),

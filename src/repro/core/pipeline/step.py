@@ -38,7 +38,7 @@ import jax.numpy as jnp
 
 from ..api import SimModel
 from ..calendar import Fallback, extract_sorted
-from ..events import compact_mask, concat_batches, truncate
+from ..events import concat_batches, take_first
 from ..placement import Placement
 from . import names
 from . import rebalance, routers, schedulers, steal  # noqa: F401  (registration imports)
@@ -108,9 +108,8 @@ def make_step(model: SimModel, cfg: EngineConfig, placement: Placement
                                                             pl, cfg)
 
             keep = prod.valid & ~send & ~late_prod & ~oob
-            kept = compact_mask(prod, keep)
-            fb = Fallback(truncate(kept, cfg.fallback_cap))
-            fb_ovf = jnp.sum(kept.valid[cfg.fallback_cap:].astype(jnp.int32))
+            kept, fb_ovf = take_first(prod, keep, cfg.fallback_cap)
+            fb = Fallback(kept)
 
         with jax.named_scope(names.EXCHANGE):
             routed = router.exchange(route_buf, pl, cfg)

@@ -11,9 +11,10 @@
   engine-level "same bits, different schedule" equivalence vs the dense
   rounds loop — the hypothesis round-trip properties live in
   test_property.py;
-* event-batch helpers (compact_mask / concat_batches / truncate) preserve
-  the valid-event multiset — the algebra `route` and `deliver` stages lean
-  on (property-style over seeded random batches, no hypothesis dependency).
+* event-batch helpers (concat_batches / take_first) preserve the
+  valid-event multiset — the algebra `route` and `deliver` stages lean on
+  (property-style over seeded random batches, no hypothesis dependency) —
+  and take_first selects what a plain numpy loop selects.
 """
 import jax
 import jax.numpy as jnp
@@ -21,8 +22,8 @@ import numpy as np
 import pytest
 
 from repro.core import EngineConfig, ParsirEngine
-from repro.core.events import (EventBatch, compact, compact_mask,
-                               concat_batches, truncate)
+from repro.core.events import (EventBatch, concat_batches, empty_batch,
+                               take_first)
 from repro.core.pipeline import (ROUTERS, SCHEDULERS, STEAL_POLICIES,
                                  Scheduler, pack_slice, register_scheduler,
                                  resolve_scheduler, unpack_slice)
@@ -288,23 +289,80 @@ def test_event_batch_algebra_preserves_valid_multiset(trial):
     cat = concat_batches(a, b)
     assert _multiset(cat) == sorted(_multiset(a) + _multiset(b))
 
-    # compact_mask keeps exactly the selected sub-multiset, front-compacted
+    # take_first keeps exactly the selected sub-multiset, front-compacted
     # in stable order (the engine always selects a subset: send ⊆ valid).
     mask = jnp.asarray(rng.random(cat.capacity) < 0.5) & cat.valid
-    sel = compact_mask(cat, mask)
-    assert _multiset(sel) == _multiset(cat._replace(valid=cat.valid & mask))
+    sel, spill = take_first(cat, mask, cat.capacity)
+    assert _multiset(sel) == _multiset(cat._replace(valid=mask))
     v = np.asarray(sel.valid)
     k = int(v.sum())
-    assert np.all(v[:k]) and not np.any(v[k:])
+    assert int(spill) == 0 and np.all(v[:k]) and not np.any(v[k:])
     np.testing.assert_array_equal(np.asarray(sel.dst)[:k],
                                   np.asarray(cat.dst)[np.asarray(mask)])
 
-    # truncate-after-compact partitions the multiset: kept + countable drops
-    # — exactly how the route/fallback stages account overflow.
-    c = compact(cat)
-    cap = int(rng.integers(1, c.capacity + 1))
-    kept, spilled = truncate(c, cap), np.asarray(c.valid)[cap:]
+    # a smaller cap partitions the multiset: kept + countable drops —
+    # exactly how the route/fallback stages account overflow.
+    cap = int(rng.integers(1, cat.capacity + 1))
+    kept, spill = take_first(cat, cat.valid, cap)
     total = len(_multiset(cat))
-    assert len(_multiset(kept)) + int(spilled.sum()) == total
+    assert int(spill) == max(total - cap, 0)
+    assert len(_multiset(kept)) + int(spill) == total
     if cap >= total:
         assert _multiset(kept) == _multiset(cat)
+
+
+def _take_first_np(b: EventBatch, mask, cap):
+    """Plain numpy reference: the first ``cap`` selected events in order,
+    empty fill after them, and how many were selected."""
+    idx = np.flatnonzero(np.asarray(mask))[:cap]
+    out = [np.array(f) for f in empty_batch(cap)]
+    for field, x in zip(out, b):
+        field[:len(idx)] = np.asarray(x)[idx]
+    return EventBatch(*out), int(np.asarray(mask).sum())
+
+
+_CAP = 16
+
+
+def _take_first_mask(case, rng, size):
+    if case == "empty":
+        return np.zeros(size, bool)
+    if case == "all":
+        return np.ones(size, bool)
+    if case in ("cap", "cap+1"):
+        m = np.zeros(size, bool)
+        m[rng.choice(size, _CAP + (case == "cap+1"), replace=False)] = True
+        return m
+    if case == "last":
+        return np.arange(size) == size - 1
+    return rng.random(size) < 0.02            # about 2% density
+
+
+@pytest.mark.parametrize("case", ["empty", "all", "cap", "cap+1", "last",
+                                  "sparse", "vmapped"])
+def test_take_first_matches_numpy_reference(case):
+    # the route buffer and the fallback keep select this way: the first cap
+    # selected events in batch order, empty fill after them, and the count
+    # of selected events that did not fit.
+    rng = np.random.default_rng(7)
+    size, rows = 2048, 3 if case == "vmapped" else 1
+    batches = [_rand_batch(rng, size) for _ in range(rows)]
+    masks = [jnp.asarray(_take_first_mask(
+        "sparse" if case == "vmapped" else case, rng, size)) for _ in batches]
+    # every selected event is valid; about half the others are valid too
+    batches = [b._replace(valid=m | jnp.asarray(rng.random(size) < 0.5))
+               for b, m in zip(batches, masks)]
+    if case == "vmapped":
+        stack = EventBatch(*(jnp.stack(f) for f in zip(*batches)))
+        out, spill = jax.vmap(lambda b, m: take_first(b, m, _CAP))(
+            stack, jnp.stack(masks))
+        got = [(EventBatch(*(f[r] for f in out)), spill[r])
+               for r in range(rows)]
+    else:
+        got = [take_first(batches[0], masks[0], _CAP)]
+    for (out, spill), b, m in zip(got, batches, masks):
+        want, want_n = _take_first_np(b, m, _CAP)
+        assert int(spill) == max(want_n - _CAP, 0)
+        for x, y in zip(out, want):
+            assert x.shape == (_CAP,) and x.dtype == y.dtype
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))

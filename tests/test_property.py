@@ -7,7 +7,7 @@
 * the width-packer (batch_impl='packed') is an exact permutation: pack →
   unpack round-trips the (ts, seed, payload, cnt) slice bit-for-bit, the
   work list is stable by (round, row), and no vmap tile mixes rounds;
-* the event-batch algebra (compact_mask / concat_batches / truncate) the
+* the event-batch algebra (concat_batches / take_first) the
   route/deliver stages lean on preserves the valid-event multiset;
 * the arena stack allocator keeps the free-region invariant and LIFO reuse;
 * placement is a partition (every object owned by exactly one device);
@@ -270,12 +270,11 @@ def _valid_multiset(b):
 def test_compact_mask_preserves_valid_multiset(rows):
     b = _mk_batch(rows)
     mask = jnp.asarray([r[4] for r in rows]) & b.valid
-    out = ev.compact_mask(b, mask)
-    assert _valid_multiset(out) == _valid_multiset(
-        b._replace(valid=b.valid & mask))
+    out, spill = ev.take_first(b, mask, b.capacity)
+    assert _valid_multiset(out) == _valid_multiset(b._replace(valid=mask))
     v = np.asarray(out.valid)
     k = int(v.sum())
-    assert np.all(v[:k]) and not np.any(v[k:])
+    assert int(spill) == 0 and np.all(v[:k]) and not np.any(v[k:])
 
 
 @given(_batch_rows, _batch_rows)
@@ -287,9 +286,10 @@ def test_concat_batches_preserves_valid_multiset(rows_a, rows_b):
 
 @given(_batch_rows, st.integers(1, 64))
 def test_truncate_partitions_valid_multiset(rows, cap):
-    b = ev.compact(_mk_batch(rows))
-    kept = ev.truncate(b, cap)
-    n_spill = int(np.asarray(b.valid)[cap:].sum())
+    b = _mk_batch(rows)
+    kept, spill = ev.take_first(b, b.valid, cap)
+    n_spill = int(spill)
+    assert n_spill == max(int(np.asarray(b.valid).sum()) - cap, 0)
     assert len(_valid_multiset(kept)) + n_spill == len(_valid_multiset(b))
     if n_spill == 0:
         assert _valid_multiset(kept) == _valid_multiset(b)

@@ -122,10 +122,10 @@ def test_phold_table2_drain_compiles_for_v5e(topo):
     _fits(eng._drain_sm.lower(state, bound).compile())
 
 
-def test_phold_table2_run_indexes_no_payload_row(topo):
-    # The rounds loop's handler writes PHOLD's touch window and reallocated
-    # nodes as masked dense updates: the compiled Table II `run` (the
-    # benchmark's program) holds no scatter or gather over the payload.
+@pytest.fixture(scope="module")
+def table2_run(topo):
+    """The benchmark's program: the compiled Table II `run` on one chip,
+    with its model and engine config."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -134,10 +134,10 @@ def test_phold_table2_run_indexes_no_payload_row(topo):
     from repro.workloads.registry import get_workload
 
     model = get_workload("phold")          # PholdParams defaults: Table II
-    p = model.params
-    cfg = EngineConfig(lookahead=p.lookahead, n_buckets=16, bucket_cap=256,
-                       route_cap=8192, fallback_cap=8192, route="allgather",
-                       scheduler="batch", batch_impl="rounds")
+    cfg = EngineConfig(lookahead=model.params.lookahead, n_buckets=16,
+                       bucket_cap=256, route_cap=8192, fallback_cap=8192,
+                       route="allgather", scheduler="batch",
+                       batch_impl="rounds")
     shapes = jax.eval_shape(ParsirEngine(model, cfg).init)
     mesh = Mesh(np.array(topo.devices[:1]), (AXIS,))
     eng = ParsirEngine(model, cfg, mesh=mesh)
@@ -145,8 +145,39 @@ def test_phold_table2_run_indexes_no_payload_row(topo):
         lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
                                        sharding=eng._sharding), shapes)
     n = jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P()))
-    hlo = eng._run_sm.lower(state, n).compile().as_text()
+    return eng._run_sm.lower(state, n).compile().as_text(), model, cfg
+
+
+def test_phold_table2_run_indexes_no_payload_row(table2_run):
+    # The rounds loop's handler writes PHOLD's touch window and reallocated
+    # nodes as masked dense updates: the compiled Table II `run` (the
+    # benchmark's program) holds no scatter or gather over the payload.
+    hlo, model, _ = table2_run
+    p = model.params
     nodes = p.n_objects * p.state_nodes
     assert payload_index_ops(hlo, nodes * p.lanes) == []
     # the parser does see indexed ops: the allocator's stack keeps its own.
     assert payload_index_ops(hlo, nodes)
+
+
+_YIELDS = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = \(?\w+\[([\d,]*)\]"
+                     r"[^=\n]*?\s(sort|gather)\(", re.M)
+
+
+def slot_wide_ops(hlo: str, n_elems: int) -> list[str]:
+    """The sorts and gathers in HLO text that yield ``n_elems`` elements
+    (a sort's first result, under any layout)."""
+    return [m.group(0).strip() for m in _YIELDS.finditer(hlo)
+            if _elems(m.group(1)) == n_elems]
+
+
+def test_phold_table2_route_sorts_and_gathers_no_slot_batch(table2_run):
+    # Route picks the route buffer and the fallback by a prefix sum and a
+    # search, so the compiled Table II `run` neither sorts the emitted
+    # slots plus the fallback (270,336 at Table II) nor gathers a field at
+    # every one of them; its gathers yield route_cap or fallback_cap rows.
+    hlo, model, cfg = table2_run
+    slots = model.params.n_objects * cfg.bucket_cap + cfg.fallback_cap
+    assert slot_wide_ops(hlo, slots) == []
+    # the parser does see sorts: extract's bucket sort keeps its own.
+    assert slot_wide_ops(hlo, model.params.n_objects * cfg.bucket_cap)
