@@ -18,7 +18,8 @@ spinlocks: slot assignment by scan instead of contention).
 
 Extraction in the *current* epoch needs no synchronization at all, mirroring the
 paper's lock-free fast path: the SPMD owner is the only reader/writer, and the
-lookahead guarantees nobody inserts into the live bucket.
+lookahead guarantees nobody inserts into the live bucket.  The drained bucket
+is ordered per object by one stable sort keyed (ts, seed).
 
 Overflow (bucket capacity exceeded) is counted and returned — never silent; the
 conservative engine treats a nonzero count as a hard error at the driver level.
@@ -120,7 +121,9 @@ def extract_sorted(cal: Calendar, epoch: jax.Array):
     Returns (calendar-with-cleared-bucket, ts, seed, payload, cnt_b) where the
     event arrays are [n_local, cap] with invalid slots at ts=+inf.  This is the
     paper's lock-free current-epoch extraction — plus the batch ordering that
-    per-object causality requires.
+    per-object causality requires: one stable sort keyed (ts, seed), so
+    equal keys keep their slot order and the dead slots come last, ordered
+    by seed and then by slot.
     """
     n_local, n_buckets, cap = cal.ts.shape
     b = (epoch % n_buckets).astype(jnp.int32)
@@ -132,15 +135,10 @@ def extract_sorted(cal: Calendar, epoch: jax.Array):
     live = jnp.arange(cap, dtype=jnp.int32)[None, :] < cnt_b[:, None]
     ts = jnp.where(live, ts, jnp.inf)
 
-    # lexicographic (ts, seed): two stable argsorts composed.
-    p1 = jnp.argsort(seed, axis=1, stable=True)
-    ts1 = jnp.take_along_axis(ts, p1, axis=1)
-    p2 = jnp.argsort(ts1, axis=1, stable=True)
-    order = jnp.take_along_axis(p1, p2, axis=1)
-
-    ts = jnp.take_along_axis(ts, order, axis=1)
-    seed = jnp.take_along_axis(seed, order, axis=1)
-    pay = jnp.take_along_axis(pay, order, axis=1)
+    # one stable sort keyed (ts, seed), the payload carried along: ties keep
+    # slot order.
+    ts, seed, pay = jax.lax.sort((ts, seed, pay), dimension=1, is_stable=True,
+                                 num_keys=2)
 
     # clear the bucket for reuse (epoch + n_buckets).
     new_cnt = jax.lax.dynamic_update_index_in_dim(

@@ -179,5 +179,16 @@ def test_phold_table2_route_sorts_and_gathers_no_slot_batch(table2_run):
     hlo, model, cfg = table2_run
     slots = model.params.n_objects * cfg.bucket_cap + cfg.fallback_cap
     assert slot_wide_ops(hlo, slots) == []
-    # the parser does see sorts: extract's bucket sort keeps its own.
+    # the parser does see sorts: extract's one bucket sort keeps its own.
     assert slot_wide_ops(hlo, model.params.n_objects * cfg.bucket_cap)
+
+
+def test_phold_table2_extract_sorts_once_and_gathers_no_slot(table2_run):
+    # Extract orders each drained bucket with one stable sort keyed
+    # (ts, seed), the payload carried as an operand: the compiled Table II
+    # `run` holds exactly one sort over the n_objects x bucket_cap slots
+    # (262,144 at Table II) and no gather that yields one element per slot.
+    hlo, model, cfg = table2_run
+    ops = slot_wide_ops(hlo, model.params.n_objects * cfg.bucket_cap)
+    assert [op for op in ops if op.endswith("gather(")] == []
+    assert len([op for op in ops if op.endswith("sort(")]) == 1
